@@ -5,27 +5,22 @@ required warm-up memory, WarmSwap vs Prebaking vs Baseline.
 Driven by the checked-in ``benchmarks/scenarios/sharing_fig7.json`` spec
 (single-worker engine) through the experiments CLI's ``run_file``. Runs twice:
 once with the PAPER's measured cost numbers (Table 2; the faithful simulation)
-and once with THIS machine's measured cold-start costs (from bench_coldstart
-artifacts when present) — the measured variant is the same spec with its
-``cost`` component overridden to ``scalar`` + measured kwargs."""
+and once with the cold-start costs that ``bench_coldstart`` measured earlier in the
+same ``benchmarks.run`` invocation, when it ran — the measured variant is the
+same spec with its ``cost`` component overridden to ``scalar`` + measured
+kwargs. Nothing is read from an earlier run's artifacts."""
 from __future__ import annotations
 
-import json
-import os
 from typing import Dict, Optional
 
-from benchmarks.common import (RESULTS_DIR, emit, save_json, scenario_path,
-                               smoke_mode, validated_samples)
+from benchmarks.common import (emit, save_json, scenario_path, smoke_mode,
+                               validated_samples)
 
 
-def _measured_cost_kwargs() -> Optional[Dict]:
-    """Scalar-cost-model kwargs from this machine's bench_coldstart artifact,
-    or None when it has not been produced yet."""
-    path = os.path.join(RESULTS_DIR, "bench_coldstart.json")
-    if not os.path.exists(path):
-        return None
-    rows = json.load(open(path))
-    rnn = rows.get("rnn_serving")
+def _measured_cost_kwargs(coldstart_rows: Dict) -> Optional[Dict]:
+    """Scalar-cost-model kwargs from ``bench_coldstart``'s rows, or None when
+    they hold no ``rnn_serving`` row."""
+    rnn = coldstart_rows.get("rnn_serving")
     if not rnn:
         return None
     return {
@@ -36,13 +31,16 @@ def _measured_cost_kwargs() -> Optional[Dict]:
     }
 
 
-def run() -> Dict:
+def run(coldstart_rows: Optional[Dict] = None) -> Dict:
+    """Run the Fig. 7 spec with the paper's costs, and also with the costs in
+    ``coldstart_rows`` (``bench_coldstart.run()``'s output) when given."""
     from repro.experiments import run_file
 
     smoke = smoke_mode()
     out: Dict = {}
     variants: Dict[str, Optional[Dict]] = {"paper_costs": None}
-    measured = _measured_cost_kwargs()
+    measured = (_measured_cost_kwargs(coldstart_rows)
+                if coldstart_rows is not None else None)
     if measured is not None:
         variants["measured_costs"] = {
             "cost.name": "scalar", "cost.kwargs": measured}

@@ -12,7 +12,9 @@ results/.  Mapping to the paper:
                          only — Table 2 (bulk / lazy / no-pageserver /
                          no-lazy)
     bench_metadata   ->  Table 3 (metadata vs image size)
-    bench_sharing    ->  Fig. 7 + 88% memory headline (Azure-trace simulation)
+    bench_sharing    ->  Fig. 7 + 88% memory headline (Azure-trace simulation);
+                         with this run's coldstart costs too when coldstart
+                         ran before it in the same invocation
     bench_fleet      ->  multi-worker fleet sweep (workers x capacity x skew x
                          sharing), placement + pre-warm policy comparison,
                          queue-accurate P50/P95/P99 per rate quartile
@@ -37,6 +39,7 @@ import time
 import traceback
 
 from benchmarks.common import save_json, set_smoke
+from repro.core.compile_cache import enable_compile_cache
 
 BENCHES = ["coldstart", "policies", "metadata", "sharing", "fleet", "kernels",
            "roofline"]
@@ -102,6 +105,7 @@ def main() -> None:
                     help="CI-sized runs for the simulation suites "
                          "(sharing, fleet); pair with --only")
     args = ap.parse_args()
+    enable_compile_cache()
     set_smoke(args.smoke)
     todo = args.only.split(",") if args.only else BENCHES
 
@@ -114,7 +118,11 @@ def main() -> None:
         t0 = time.perf_counter()
         try:
             mod = __import__(mod_name, fromlist=["run"])
-            outs[name] = mod.run()
+            if name == "sharing" and "coldstart" in outs:
+                # measured costs come only from this invocation's coldstart
+                outs[name] = mod.run(outs["coldstart"])
+            else:
+                outs[name] = mod.run()
             wall = time.perf_counter() - t0
             cells[name] = {"ok": True, "wall_clock_s": wall}
             print(f"# {name}: ok ({wall:.1f}s)", file=sys.stderr)

@@ -19,6 +19,7 @@ from repro.core import (
     RestorePolicy,
 )
 from repro.core import workloads as wl
+from repro.core.compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -82,5 +83,6 @@ def scenario_quickstart() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()
     scenario_quickstart()

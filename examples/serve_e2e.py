@@ -13,6 +13,7 @@ import numpy as np
 
 from repro.configs import get_reduced
 from repro.core import DependencyManager, RestorePolicy
+from repro.core.compile_cache import enable_compile_cache
 from repro.models.transformer import init_params
 from repro.runtime import ReplicaSet
 from repro.serving import ServeConfig, ServingEngine
@@ -76,4 +77,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 
 from repro.checkpoint import CheckpointConfig
 from repro.configs import get_config
+from repro.core.compile_cache import enable_compile_cache
 from repro.data import DataConfig, SyntheticTokenPipeline
 from repro.models.api import make_train_step
 from repro.models.transformer import init_params
@@ -57,4 +58,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

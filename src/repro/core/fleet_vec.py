@@ -202,48 +202,45 @@ def fast_path_reason(traces: Union[List[Trace], TraceStream], method: str,
 
 
 # ------------------------------------------------------------------ jax scan
-_SCAN_FN: List[Optional[Callable]] = []
+_SCAN_FN: List[Callable] = []
 
 
-def _get_scan_fn() -> Optional[Callable]:
-    """Build (once) the jitted cap=1 group recursion, or ``None`` when jax
-    is unavailable — the caller silently falls back to the numpy solver."""
+def _get_scan_fn() -> Callable:
+    """Build (once) the jitted cap=1 group recursion. It runs on the CPU
+    device: the fleet simulator is a host program, and the accelerator (if
+    any) stays free for the system it simulates."""
     if _SCAN_FN:
         return _SCAN_FN[0]
-    try:
-        import jax
-        import jax.numpy as jnp
-        from jax.experimental import enable_x64
+    import jax
+    import jax.numpy as jnp
 
-        @jax.jit
-        def body(tp, warm_s, cold_s, wm, cold60, ka):
-            def step(state, t):
-                alive, free, exp = state
-                alive2 = jnp.logical_and(alive, exp >= t)
-                queued = jnp.logical_and(alive2, free > t)
-                start = jnp.where(queued, free, t)
-                svc = jnp.where(alive2, warm_s, cold_s)
-                svc60 = jnp.where(alive2, wm, cold60)
-                wait = (start - t) * 60.0
-                sample = wait + svc
-                free2 = start + svc60
-                exp2 = free2 + ka
-                return ((jnp.bool_(True), free2, exp2),
-                        (sample, wait, start, jnp.logical_not(alive2),
-                         queued, exp2))
-            z = jnp.zeros((), tp.dtype)
-            _, ys = jax.lax.scan(step, (jnp.bool_(False), z, z), tp)
-            return ys
+    @jax.jit
+    def body(tp, warm_s, cold_s, wm, cold60, ka):
+        def step(state, t):
+            alive, free, exp = state
+            alive2 = jnp.logical_and(alive, exp >= t)
+            queued = jnp.logical_and(alive2, free > t)
+            start = jnp.where(queued, free, t)
+            svc = jnp.where(alive2, warm_s, cold_s)
+            svc60 = jnp.where(alive2, wm, cold60)
+            wait = (start - t) * 60.0
+            sample = wait + svc
+            free2 = start + svc60
+            exp2 = free2 + ka
+            return ((jnp.bool_(True), free2, exp2),
+                    (sample, wait, start, jnp.logical_not(alive2),
+                     queued, exp2))
+        z = jnp.zeros((), tp.dtype)
+        _, ys = jax.lax.scan(step, (jnp.bool_(False), z, z), tp)
+        return ys
 
-        def call(tp, *consts):
-            # the session conftest disables global x64; the engine contract
-            # is float64, so flip it on locally for trace + execution
-            with enable_x64():
-                return body(tp, *consts)
-        # Process-wide jit cache: compile the scan body once per process.
-        _SCAN_FN.append(call)     # repro-lint: allow[module-mutable]
-    except Exception:
-        _SCAN_FN.append(None)     # repro-lint: allow[module-mutable]
+    def call(tp, *consts):
+        # the session conftest disables global x64; the engine contract is
+        # float64, so flip it on locally for trace + execution
+        with jax.default_device(jax.devices("cpu")[0]), jax.enable_x64(True):
+            return body(tp, *consts)
+    # Process-wide jit cache: compile the scan body once per process.
+    _SCAN_FN.append(call)     # repro-lint: allow[module-mutable]
     return _SCAN_FN[0]
 
 
@@ -253,10 +250,8 @@ def _solve_group_scan(t_g, gl, warm_s, cold_s, wm, cold60, ka,
     recursion on a single rotating instance (queued requests chain through
     the carried ``free`` time in FIFO order). Returns the same
     ``(n_cold, n_warm_imm, n_disp, recs)`` tuple as the scalar/vector
-    solver, or ``None`` when jax is unavailable."""
+    solver."""
     fn = _get_scan_fn()
-    if fn is None:
-        return None
     L = len(t_g)
     pad = 1 << max(6, int(L - 1).bit_length())   # bucket sizes: few recompiles
     tp = np.full(pad, np.inf)
@@ -296,10 +291,8 @@ def _solve_group(t_g: np.ndarray, g_idx: np.ndarray, cap: Optional[int],
     wm = warm_s / 60.0
     cold60 = cold_s / 60.0
     if use_scan and cap == 1:
-        out = _solve_group_scan(t_g, g_idx.tolist(), warm_s, cold_s, wm,
-                                cold60, ka, samples, waits)
-        if out is not None:
-            return out
+        return _solve_group_scan(t_g, g_idx.tolist(), warm_s, cold_s, wm,
+                                 cold60, ka, samples, waits)
     L = len(t_g)
     tl = t_g.tolist()
     gl = g_idx.tolist()

@@ -104,6 +104,14 @@ def run_point(spec: Mapping[str, Any]) -> Dict[str, Any]:
     return d
 
 
+def _cpu_only_worker() -> None:
+    """Pool initializer: a sweep worker is a host program. Pinning its JAX to
+    the CPU platform before any backend starts keeps it off the accelerator,
+    which belongs to the parent process."""
+    import jax
+    jax.config.update("jax_platforms", "cpu")
+
+
 def run_sweep(
     base: Scenario,
     axes: Mapping[str, Sequence[Any]],
@@ -181,7 +189,8 @@ def run_sweep(
     if todo:
         if parallel > 1:
             ctx = multiprocessing.get_context(mp_context)
-            with ctx.Pool(processes=min(parallel, len(todo))) as pool:
+            with ctx.Pool(processes=min(parallel, len(todo)),
+                          initializer=_cpu_only_worker) as pool:
                 # ordered imap: results stream back (and append to the
                 # store) in grid order, making serial == parallel stores
                 # byte-identical

@@ -1,8 +1,9 @@
 """Pallas TPU kernels for the perf-critical compute/data-movement hot spots.
 
 Each kernel ships as <name>/kernel.py (pl.pallas_call + explicit BlockSpec VMEM
-tiling), <name>/ops.py (jitted public wrapper; interpret-mode on CPU), and
-<name>/ref.py (pure-jnp oracle used by the allclose test sweeps).
+tiling), <name>/ops.py (jitted public wrapper: compiled for the TPU; anywhere
+else it raises unless the caller passes ``interpret=True``), and <name>/ref.py
+(pure-jnp oracle used by the allclose test sweeps).
 
   * flash_attention — blockwise online-softmax prefill attention
                       (causal / SWA / softcap / GQA)

@@ -1,4 +1,5 @@
-"""Jitted public wrapper for the flash-decode kernel."""
+"""Jitted public wrapper for the flash-decode kernel (off-TPU only with
+``interpret=True``)."""
 from __future__ import annotations
 
 from functools import partial
@@ -6,11 +7,8 @@ from typing import Optional
 
 import jax
 
+from repro.kernels.backend import resolve_interpret
 from repro.kernels.decode_attention.kernel import decode_attention_pallas
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @partial(jax.jit, static_argnames=("softcap", "scale", "block_k", "interpret"))
@@ -23,8 +21,8 @@ def decode_attention(
     softcap: Optional[float] = None,
     scale: Optional[float] = None,
     block_k: int = 512,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> jax.Array:
-    interp = (not _on_tpu()) if interpret is None else interpret
-    return decode_attention_pallas(q, k_cache, v_cache, valid, softcap=softcap,
-                                   scale=scale, block_k=block_k, interpret=interp)
+    return decode_attention_pallas(
+        q, k_cache, v_cache, valid, softcap=softcap, scale=scale, block_k=block_k,
+        interpret=resolve_interpret("decode_attention", interpret))

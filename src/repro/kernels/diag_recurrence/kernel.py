@@ -32,12 +32,11 @@ def _recurrence_kernel(a_ref, b_ref, h0_ref, h_ref, carry, *, chunk: int):
     def _init():
         carry[...] = h0_ref[0]
 
-    a = a_ref[0]            # (chunk, bc)
-    b = b_ref[0]
-
-    def body(t, h):
-        h = a[t] * h + b[t]
-        h_ref[0, t] = h.astype(h_ref.dtype)
+    # Index the refs, not a loaded block: a traced row of a loaded value
+    # lowers to dynamic_slice, which Pallas TPU does not implement.
+    def body(t, h):                                   # h: (1, bc)
+        h = a_ref[0, pl.ds(t, 1), :] * h + b_ref[0, pl.ds(t, 1), :]
+        h_ref[0, pl.ds(t, 1), :] = h.astype(h_ref.dtype)
         return h
 
     h = jax.lax.fori_loop(0, chunk, body, carry[...])
@@ -66,6 +65,7 @@ def diag_recurrence_pallas(
         h0 = jnp.pad(h0, ((0, 0), (0, pad_c)))
     Sp, Cp = a.shape[1], a.shape[2]
     n_chunks, n_cblocks = Sp // chunk, Cp // block_c
+    h0 = h0[:, None, :]     # (B, 1, Cp): a (1, block_c) block spans a whole dim
 
     kernel = functools.partial(_recurrence_kernel, chunk=chunk)
     h_all = pl.pallas_call(
@@ -76,12 +76,13 @@ def diag_recurrence_pallas(
                          lambda bc, j, n=n_cblocks: (bc // n, j, bc % n)),
             pl.BlockSpec((1, chunk, block_c),
                          lambda bc, j, n=n_cblocks: (bc // n, j, bc % n)),
-            pl.BlockSpec((1, block_c), lambda bc, j, n=n_cblocks: (bc // n, bc % n)),
+            pl.BlockSpec((1, 1, block_c),
+                         lambda bc, j, n=n_cblocks: (bc // n, 0, bc % n)),
         ],
         out_specs=pl.BlockSpec((1, chunk, block_c),
                                lambda bc, j, n=n_cblocks: (bc // n, j, bc % n)),
         out_shape=jax.ShapeDtypeStruct((B, Sp, Cp), a.dtype),
-        scratch_shapes=[pltpu.VMEM((block_c,), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, block_c), jnp.float32)],
         interpret=interpret,
     )(a, b, h0)
     h_all = h_all[:, :S, :C]

@@ -1,4 +1,5 @@
-"""Jitted public wrapper for the diagonal-recurrence kernel."""
+"""Jitted public wrapper for the diagonal-recurrence kernel (off-TPU only with
+``interpret=True``)."""
 from __future__ import annotations
 
 from functools import partial
@@ -6,18 +7,15 @@ from typing import Tuple
 
 import jax
 
+from repro.kernels.backend import resolve_interpret
 from repro.kernels.diag_recurrence.kernel import diag_recurrence_pallas
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @partial(jax.jit, static_argnames=("chunk", "block_c", "interpret"))
 def diag_recurrence(
     a: jax.Array, b: jax.Array, h0: jax.Array,
-    *, chunk: int = 128, block_c: int = 2048, interpret=None,
+    *, chunk: int = 128, block_c: int = 2048, interpret: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
-    interp = (not _on_tpu()) if interpret is None else interpret
-    return diag_recurrence_pallas(a, b, h0, chunk=chunk, block_c=block_c,
-                                  interpret=interp)
+    return diag_recurrence_pallas(
+        a, b, h0, chunk=chunk, block_c=block_c,
+        interpret=resolve_interpret("diag_recurrence", interpret))

@@ -1,8 +1,9 @@
 """Jitted public wrapper for the flash-attention kernel.
 
-``flash_attention(...)`` dispatches to the Pallas kernel on TPU and to interpret mode
-elsewhere (this container is CPU-only; interpret mode executes the kernel body
-faithfully for validation). The reference semantics live in ``ref.py``.
+``flash_attention(...)`` runs the Pallas kernel compiled for the TPU. Off-TPU it
+raises unless the caller passes ``interpret=True``, which executes the kernel body
+faithfully in the Pallas interpreter for validation. The reference semantics live
+in ``ref.py``.
 """
 from __future__ import annotations
 
@@ -11,11 +12,8 @@ from typing import Optional
 
 import jax
 
+from repro.kernels.backend import resolve_interpret
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "softcap", "scale",
@@ -31,9 +29,9 @@ def flash_attention(
     scale: Optional[float] = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> jax.Array:
-    interp = (not _on_tpu()) if interpret is None else interpret
     return flash_attention_pallas(
         q, k, v, causal=causal, window=window, softcap=softcap, scale=scale,
-        block_q=block_q, block_k=block_k, interpret=interp)
+        block_q=block_q, block_k=block_k,
+        interpret=resolve_interpret("flash_attention", interpret))

@@ -6,5 +6,5 @@ import jax.numpy as jnp
 
 
 def page_gather_ref(pool: jax.Array, page_ids: jax.Array) -> jax.Array:
-    """pool: (P, E); page_ids: (K,) int32 -> out (K, E) = pool[page_ids]."""
+    """pool: (P, R, L); page_ids: (K,) int32 -> out (K, R, L) = pool[page_ids]."""
     return jnp.take(pool, page_ids, axis=0)

@@ -1,5 +1,10 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+# The dry run compiles for 512 placeholder devices of the host's CPU platform.
+# Pin that platform (on a chip machine JAX would otherwise default to the one
+# accelerator) and add the device count to any XLA flags already set.
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+    os.environ.get("XLA_FLAGS"), "--xla_force_host_platform_device_count=512")))
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
@@ -19,8 +24,9 @@ Usage:
   python -m repro.launch.dryrun --arch gemma2_27b --shape train_4k --mesh single
   python -m repro.launch.dryrun --all --mesh both --out results/dryrun
 """
-# NOTE: no `from __future__ import annotations` here — the XLA_FLAGS lines above must
-# stay the very first statements of the module (jax locks device count on first init).
+# NOTE: no `from __future__ import annotations` here — the environment lines above
+# must stay the very first statements of the module (jax locks the platform and the
+# device count on first init).
 import argparse
 import json
 import sys
@@ -30,6 +36,10 @@ from functools import partial
 from typing import Any, Dict, Optional
 
 import numpy as np
+
+#: The chip the production meshes are made of (``jax.Device.device_kind`` of a
+#: TPU v5e); its peaks price the roofline terms.
+TARGET_DEVICE_KIND = "TPU v5 lite"
 
 
 def build_cell(arch: str, shape_name: str, mesh):
@@ -134,9 +144,8 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
     import jax
 
     from repro.launch.hlo_walk import analyze_module
-    from repro.launch.hlo_analysis import (cost_summary,
-                                           memory_summary, roofline_terms,
-                                           PEAK_FLOPS)
+    from repro.launch.hlo_analysis import (cost_summary, device_peaks,
+                                           memory_summary, roofline_terms)
     from repro.launch.mesh import make_production_mesh
     from repro.models.config import SHAPES
 
@@ -145,7 +154,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
     n_chips = int(np.prod(list(mesh.shape.values())))
     record: Dict[str, Any] = {
         "arch": arch, "shape": shape_name, "mesh": mesh_kind, "chips": n_chips,
-        "status": "ok",
+        "device_kind": TARGET_DEVICE_KIND, "status": "ok",
     }
     try:
         fn, arg_specs, out_shardings, donate, cfg = build_cell(arch, shape_name, mesh)
@@ -178,11 +187,13 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
         record["model_flops_global"] = float(model_flops)
         record["model_flops_per_device"] = float(model_flops / n_chips)
         rt = roofline_terms(walk["flops"], walk["bytes"],
-                            walk["collective_ring_weighted_bytes"])
+                            walk["collective_ring_weighted_bytes"],
+                            device_kind=TARGET_DEVICE_KIND)
         rt["useful_flops_ratio"] = (record["model_flops_per_device"] /
                                     max(walk["flops"], 1.0))
         rt["mfu_upper_bound"] = (record["model_flops_per_device"] /
-                                 max(rt["step_lower_bound_s"], 1e-30) / PEAK_FLOPS)
+                                 max(rt["step_lower_bound_s"], 1e-30)
+                                 / device_peaks(TARGET_DEVICE_KIND)["flops"])
         record["roofline"] = rt
         if verbose:
             ma = mem.get("live_bytes", 0) / 1e9

@@ -107,17 +107,34 @@ def memory_summary(compiled) -> Dict[str, float]:
 
 
 # ------------------------------------------------------------------ roofline terms
-# TPU v5e per-chip constants (assignment-specified)
-PEAK_FLOPS = 197e12         # bf16 FLOP/s
-HBM_BW = 819e9              # bytes/s
-ICI_BW = 50e9               # bytes/s per link
+#: Per-chip peaks keyed by ``jax.Device.device_kind``. Source: Google Cloud
+#: documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM, and 1,600 Gbit/s
+#: of chip-to-chip interconnect, i.e. four links of 50 GB/s (the collective
+#: term charges one link).
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+
+
+def device_peaks(device_kind: str) -> Dict[str, float]:
+    """Peak FLOP/s and bytes/s of one chip of ``device_kind``.
+
+    Raises:
+        ValueError: no published peaks are recorded for ``device_kind``.
+    """
+    if device_kind not in PEAKS:
+        raise ValueError(f"no peak rates recorded for device kind "
+                         f"{device_kind!r} (known: {sorted(PEAKS)})")
+    return PEAKS[device_kind]
 
 
 def roofline_terms(flops_per_device: float, bytes_per_device: float,
-                   collective_bytes_per_device: float) -> Dict[str, Any]:
-    compute_s = flops_per_device / PEAK_FLOPS
-    memory_s = bytes_per_device / HBM_BW
-    collective_s = collective_bytes_per_device / ICI_BW
+                   collective_bytes_per_device: float, *,
+                   device_kind: str) -> Dict[str, Any]:
+    peak = device_peaks(device_kind)
+    compute_s = flops_per_device / peak["flops"]
+    memory_s = bytes_per_device / peak["hbm_bw"]
+    collective_s = collective_bytes_per_device / peak["ici_bw"]
     terms = {"compute_s": compute_s, "memory_s": memory_s,
              "collective_s": collective_s}
     bottleneck = max(terms, key=terms.get)

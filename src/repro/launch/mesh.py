@@ -29,10 +29,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
             f"need {n} devices for mesh {shape}, have {len(devices)} — the dry-run "
             "launcher must set XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             "before importing jax")
-    try:
-        return jax.make_mesh(shape, axes, devices=devices[:n])
-    except TypeError:
-        return Mesh(np.asarray(devices[:n]).reshape(shape), axes)
+    return jax.make_mesh(shape, axes, devices=devices[:n])
 
 
 def make_local_mesh(model_axis: int = 1) -> Mesh:

@@ -11,8 +11,33 @@ from __future__ import annotations
 
 import argparse
 import time
+from typing import Any, Dict, List, Sequence, Tuple
 
 import numpy as np
+
+
+def start_replica(manager, image_id: str, cfg, serve_cfg, policy
+                  ) -> Tuple[Any, float]:
+    """Bring one serving replica up by live migration from ``manager``'s pool;
+    returns ``(engine, seconds)``."""
+    from repro.serving import ServingEngine
+    t0 = time.perf_counter()
+    engine = ServingEngine.from_pool(manager, image_id, cfg, serve_cfg,
+                                     policy=policy)
+    return engine, time.perf_counter() - t0
+
+
+def serve_prompts(engine, prompts: Sequence[np.ndarray]) -> Dict[str, Any]:
+    """Submit ``prompts``, run the engine until every one is done, and report
+    what it served: the engine's metrics plus ``seconds``, ``n_tokens`` and
+    each request's generated ``tokens`` in submission order."""
+    rids = [engine.submit(p) for p in prompts]
+    t0 = time.perf_counter()
+    engine.run_until_done()
+    seconds = time.perf_counter() - t0
+    tokens: List[List[int]] = [list(engine.completed[r].tokens) for r in rids]
+    return {**engine.metrics(), "seconds": seconds,
+            "n_tokens": sum(len(t) for t in tokens), "tokens": tokens}
 
 
 def main() -> None:
@@ -35,9 +60,11 @@ def main() -> None:
 
     from repro.core import DependencyManager, RestorePolicy
     from repro.core import workloads as wl
+    from repro.core.compile_cache import enable_compile_cache
     from repro.models.transformer import init_params
-    from repro.serving import ServeConfig, ServingEngine
+    from repro.serving import ServeConfig
 
+    enable_compile_cache()
     policy = RestorePolicy(args.policy)
     mgr = DependencyManager()
 
@@ -56,24 +83,19 @@ def main() -> None:
     print(f"[serve] pool ready: {mgr.summary()['live_images']} "
           f"({mgr.pool_bytes()/1e6:.1f} MB)")
 
-    t0 = time.perf_counter()
-    engine = ServingEngine.from_pool(
+    engine, up_s = start_replica(
         mgr, image_id, cfg,
         ServeConfig(max_slots=args.slots, max_seq_len=args.max_seq,
                     max_new_tokens=args.max_new),
-        policy=policy)
-    print(f"[serve] replica cold-start via WarmSwap ({policy.value}): "
-          f"{time.perf_counter()-t0:.3f}s")
+        policy)
+    print(f"[serve] replica cold-start via WarmSwap ({policy.value}): {up_s:.3f}s")
 
     rng = np.random.default_rng(args.seed)
-    for _ in range(args.requests):
-        plen = int(rng.integers(4, min(64, args.max_seq - args.max_new)))
-        engine.submit(rng.integers(0, cfg.vocab_size, plen))
-    t1 = time.perf_counter()
-    engine.run_until_done()
-    dt = time.perf_counter() - t1
-    m = engine.metrics()
-    total_tokens = sum(len(r.tokens) for r in engine.completed.values())
+    prompts = [rng.integers(0, cfg.vocab_size,
+                            int(rng.integers(4, min(64, args.max_seq - args.max_new))))
+               for _ in range(args.requests)]
+    m = serve_prompts(engine, prompts)
+    dt, total_tokens = m["seconds"], m["n_tokens"]
     print(f"[serve] {m['completed']} requests, {total_tokens} tokens in {dt:.2f}s "
           f"({total_tokens/dt:.1f} tok/s); mean ttft={m['mean_ttft_s']*1e3:.0f}ms "
           f"mean latency={m['mean_latency_s']*1e3:.0f}ms")

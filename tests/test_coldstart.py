@@ -101,3 +101,23 @@ def test_all_policies_cold_start(stack, policy):
     r, _ = inst.invoke(req)
     assert r is not None
     assert t.total > 0
+
+
+def test_baseline_compiles_without_persistent_cache(stack, monkeypatch):
+    """Paper Fig. 3's baseline compiles from scratch: even when an entry point
+    has turned JAX's persistent compilation cache on, the baseline's compile
+    runs with it off (and it is on again afterwards)."""
+    import jax
+    _, _, orch = stack
+    seen = []
+    real_warm = wl.warm_executables
+
+    def spy(execs, params, image_id):
+        seen.append(jax.config.jax_enable_compilation_cache)
+        real_warm(execs, params, image_id)
+
+    monkeypatch.setattr(wl, "warm_executables", spy)
+    assert jax.config.jax_enable_compilation_cache
+    orch.cold_start_baseline("lr_serving")
+    assert seen == [False]
+    assert jax.config.jax_enable_compilation_cache

@@ -263,8 +263,6 @@ def test_headline_speedup_band_via_fleet_vec():
 # ---------------------------------------------------------------------------------
 
 def test_scan_path_bit_identical():
-    if _get_scan_fn() is None:
-        pytest.skip("jax unavailable: scan path disabled")
     tr = _traces(n_fns=8, horizon=1200.0, rate=6.0)
     for method in ("warmswap", "prebaking", "baseline"):
         cfg = dict(n_workers=1, max_instances_per_fn=1)
@@ -276,8 +274,6 @@ def test_scan_path_bit_identical():
 
 
 def test_scan_env_toggle(monkeypatch):
-    if _get_scan_fn() is None:
-        pytest.skip("jax unavailable: scan path disabled")
     tr = _traces(n_fns=4)
     cfg = dict(n_workers=1, max_instances_per_fn=1)
     monkeypatch.setenv("REPRO_FLEET_VEC_SCAN", "1")
@@ -285,3 +281,17 @@ def test_scan_env_toggle(monkeypatch):
     assert SCAN_STATS["groups"] > 0
     ref = _simulate_fleet_impl(tr, "warmswap", CM, FleetConfig(**cfg))
     assert_equiv(ref, vec, label="scan-env")
+
+
+def test_scan_path_runs_on_cpu_device():
+    """The scan path is host work: its outputs live on the CPU device, in the
+    engine's float64, whatever the process's default device is."""
+    import jax
+    cpu = jax.devices("cpu")[0]
+    tp = np.array([0.0, 0.01, 10.0, np.inf])     # minutes
+    ys = _get_scan_fn()(tp, 1.0, 2.0, 1.0 / 60.0, 2.0 / 60.0, 5.0)
+    assert all(y.devices() == {cpu} for y in ys)
+    sample, wait = np.asarray(ys[0]), np.asarray(ys[1])
+    assert sample.dtype == np.float64
+    # the first arrival is cold (2 s); the second, 0.6 s later, queues
+    assert sample[0] == 2.0 and wait[1] > 0

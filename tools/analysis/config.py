@@ -52,7 +52,10 @@ SHARED_STATE_SCOPE: Tuple[str, ...] = DETERMINISM_SCOPE + (
 #:     scan path (docs/SIMULATION.md, "Vectorized engine").
 #:   * ``sanitize_enabled`` — the REPRO_SANITIZE opt-in for the runtime
 #:     invariant sanitizer (docs/ANALYSIS.md, "Runtime sanitizer").
+#:   * ``cache_dir`` — JAX_COMPILATION_CACHE_DIR, where the entry points keep
+#:     the persistent compilation cache (a deployment path; never a result).
 SANCTIONED_ENVIRON: Set[Tuple[str, str]] = {
+    ("src/repro/core/compile_cache.py", "cache_dir"),
     ("benchmarks/common.py", "set_smoke"),
     ("benchmarks/common.py", "smoke_mode"),
     ("src/repro/core/fleet_vec.py", "_scan_enabled"),
