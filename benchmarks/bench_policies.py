@@ -157,7 +157,6 @@ def _run_table2() -> Dict:
                 "warm_s": median(warm),
                 "pages": getattr(stats, "pages_transferred", None),
                 "requests": getattr(stats, "requests", None),
-                "fault_wait_s": getattr(stats, "fault_wait_s", None),
             }
             emit(f"policy/{policy.value}/{fn}", median(cold) * 1e6,
                  f"warm={median(warm)*1e6:.0f}us pages="

@@ -29,6 +29,7 @@ import numpy as np
 
 from repro.core.image import ImageMetadata, LiveDependencyImage
 from repro.core.pages import materialize_leaf
+from repro.runtime.tracing import span
 
 
 class RestorePolicy(enum.Enum):
@@ -67,8 +68,6 @@ class MigrationStats:
     pages_transferred: int = 0
     bytes_transferred: int = 0
     faults: int = 0
-    fault_wait_s: float = 0.0        # time execution spent blocked on pages
-    stream_s: float = 0.0            # background streaming wall time
 
 
 class PageServer:
@@ -192,7 +191,6 @@ class RestoredImage:
             # the event, so the next wait() blocks until it resolves
 
     def _stream_all(self, skip: Sequence[str] = ()) -> None:
-        t0 = time.perf_counter()
         for key in self._table.order:      # layer order == execution order
             if key in skip or key in self._local:
                 continue
@@ -203,7 +201,6 @@ class RestoredImage:
                     # recorded in _install_error and the claim was released —
                     # keep streaming; wait_all()/fault() retry this leaf
                     continue
-        self.stats.stream_s += time.perf_counter() - t0
 
     def _start_background_stream(self, skip: Sequence[str] = ()) -> None:
         with self._claim_lock:             # two first-faults must not both stream
@@ -223,14 +220,12 @@ class RestoredImage:
             key: leaf path in the image's page table.
 
         Returns:
-            The materialized leaf array. Blocking time is accounted in
-            ``stats.fault_wait_s`` (seconds); under ``BULK`` the first fault
-            also kicks off the background stream for the remaining leaves.
+            The materialized leaf array. Under ``BULK`` the first fault also
+            kicks off the background stream for the remaining leaves.
         """
         if self._events[key].is_set() and key in self._local:
             return self._local[key]
         self.stats.faults += 1
-        t0 = time.perf_counter()
         if self.policy == RestorePolicy.LAZY:
             self._ensure_leaf(key)
         elif self.policy == RestorePolicy.BULK:
@@ -240,7 +235,6 @@ class RestoredImage:
         else:
             # NO_LAZY / NO_PAGESERVER should have pre-installed everything
             self._events[key].wait()
-        self.stats.fault_wait_s += time.perf_counter() - t0
         return self._local[key]
 
     def wait_all(self) -> None:
@@ -269,10 +263,12 @@ class RestoredImage:
 
     def as_pytree(self) -> Any:
         """Full parameter pytree (blocks until resident)."""
-        self.wait_all()
         import jax
-        leaves = [self._local[k] for k in self._table.tree_order]
-        return jax.tree_util.tree_unflatten(self.treedef, leaves)
+        t = self._table
+        with span("restore", bytes=t.nbytes_payload, pages=t.n_pages):
+            self.wait_all()
+            leaves = [self._local[k] for k in t.tree_order]
+            return jax.tree_util.tree_unflatten(self.treedef, leaves)
 
 
 class MigrationClient:

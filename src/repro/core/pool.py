@@ -33,6 +33,7 @@ import numpy as np
 from repro.core.image import LiveDependencyImage, build_image
 from repro.core.migration import LinkModel, MigrationClient, RestoredImage, RestorePolicy
 from repro.core.pages import DEFAULT_PAGE_SIZE
+from repro.runtime.tracing import span
 
 
 @dataclass
@@ -42,8 +43,6 @@ class PoolStats:
     misses: int = 0
     evictions: int = 0
     revivals: int = 0
-    build_s: float = 0.0
-    revive_s: float = 0.0
 
 
 @dataclass
@@ -359,13 +358,11 @@ class DependencyManager:
                 self._ledger.touch(image_id, img.last_used)
                 return img
             self.stats.misses += 1
-            t0 = time.perf_counter()
             if self._on_disk.get(image_id) and self.disk_dir:
                 img = LiveDependencyImage.from_disk(
                     self.disk_dir, image_id, self._treedefs[image_id])
                 img.executables = self._executables.get(image_id, {})
                 self.stats.revivals += 1
-                self.stats.revive_s += time.perf_counter() - t0
             else:
                 img = build_image(
                     image_id, self._arch_names[image_id], self._builders[image_id],
@@ -373,7 +370,6 @@ class DependencyManager:
                     executables=self._executables.get(image_id))
                 self._treedefs[image_id] = img.treedef
                 self.stats.builds += 1
-                self.stats.build_s += time.perf_counter() - t0
             self._admit(img)
             return img
 
@@ -409,15 +405,16 @@ class DependencyManager:
     ) -> RestoredImage:
         """Paper Fig. 4c: look up the image, hand metadata + a page server to the
         container's migration client."""
-        img = self._ensure_live(image_id)
-        with self._lock:
-            img.refcount += 1
-            # Live-manager LRU clock.  # repro-lint: allow[wall-clock]
-            img.last_used = time.monotonic()
-            self._ledger.acquire(image_id)
-            self._ledger.touch(image_id, img.last_used)
-        client = MigrationClient(link or self.link)
-        return client.migrate(img, policy)
+        with span("migrate", hit=int(self.has_live(image_id))):
+            img = self._ensure_live(image_id)
+            with self._lock:
+                img.refcount += 1
+                # Live-manager LRU clock.  # repro-lint: allow[wall-clock]
+                img.last_used = time.monotonic()
+                self._ledger.acquire(image_id)
+                self._ledger.touch(image_id, img.last_used)
+            client = MigrationClient(link or self.link)
+            return client.migrate(img, policy)
 
     def release(self, image_id: str) -> None:
         with self._lock:

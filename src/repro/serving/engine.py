@@ -31,6 +31,7 @@ import numpy as np
 from repro.models.api import make_serve_step_with_logits
 from repro.models.config import ArchConfig
 from repro.models.transformer import forward, init_decode_state
+from repro.runtime.tracing import span
 from repro.serving.state_utils import state_reset_slot, state_splice
 
 
@@ -70,8 +71,14 @@ class ServingEngine:
         self.params = params
         self.scfg = serve_cfg if serve_cfg is not None else ServeConfig()
         B = self.scfg.max_slots
-        self.state = init_decode_state(cfg, B, self.scfg.max_seq_len, jnp.float32)
-        self._serve_step = jax.jit(make_serve_step_with_logits(cfg))
+        with span("engine_init", slots=B, max_seq_len=self.scfg.max_seq_len):
+            self.state = init_decode_state(cfg, B, self.scfg.max_seq_len,
+                                           jnp.float32)
+            self._serve_step = jax.jit(make_serve_step_with_logits(cfg))
+        # bytes of the parameter leaves not on the device: the least that
+        # every prefill and decode step copies host->device
+        self.h2d_bytes = sum(np.asarray(x).nbytes for x in jax.tree.leaves(params)
+                             if not isinstance(x, jax.Array))
         self._queue: Deque[Request] = collections.deque()
         self._slots: List[Optional[Request]] = [None] * B
         self._next_tok = np.zeros((B, 1), np.int32)
@@ -93,17 +100,27 @@ class ServingEngine:
             if self._slots[slot] is not None or not self._queue:
                 continue
             req = self._queue.popleft()
+            queued_us = int(1e6 * (time.monotonic() - req.submitted_at))
+            with span("admit", rid=req.rid, prompt_len=len(req.prompt),
+                      queued_us=queued_us):
+                self._admit_one(req, slot)
+
+    def _admit_one(self, req: Request, slot: int) -> None:
+        with span("prefill", rid=req.rid, h2d_bytes=self.h2d_bytes):
             tokens = jnp.asarray(req.prompt[None, :], jnp.int32)
             logits, _, single = forward(
                 self.params, tokens, self.cfg, make_state=True,
                 state_len=self.scfg.max_seq_len, logits_slice=1)
-            first = self._sample(np.asarray(logits[:, -1, : self.cfg.vocab_size]))
-            req.prefilled_at = time.monotonic()
-            req.tokens.append(int(first[0]))
+            last = np.asarray(logits[:, -1, : self.cfg.vocab_size])
+        with span("sample", active=1):
+            first = self._sample(last)
+        req.prefilled_at = time.monotonic()
+        req.tokens.append(int(first[0]))
+        with span("splice", rid=req.rid):
             self.state = state_reset_slot(self.state, slot)
             self.state = state_splice(self.state, single, slot)
-            self._slots[slot] = req
-            self._next_tok[slot, 0] = first[0]
+        self._slots[slot] = req
+        self._next_tok[slot, 0] = first[0]
 
     def _sample(self, logits: np.ndarray) -> np.ndarray:
         if self.scfg.temperature <= 0:
@@ -117,13 +134,21 @@ class ServingEngine:
     # ------------------------------------------------------------------ one step
     def step(self) -> int:
         """Admit, decode one token for every active slot; returns #active."""
-        self._admit()
-        active = [i for i, r in enumerate(self._slots) if r is not None]
-        if not active:
-            return 0
-        logits, self.state = self._serve_step(
-            self.params, self.state, jnp.asarray(self._next_tok))
-        toks = self._sample(np.asarray(logits))
+        with span("step", queued=len(self._queue)):
+            self._admit()
+            active = [i for i, r in enumerate(self._slots) if r is not None]
+            if not active:
+                return 0
+            with span("decode", active=len(active), h2d_bytes=self.h2d_bytes):
+                logits, self.state = self._serve_step(
+                    self.params, self.state, jnp.asarray(self._next_tok))
+                logits = np.asarray(logits)
+            with span("sample", active=len(active)):
+                self._retire(active, self._sample(logits))
+            return len(active)
+
+    def _retire(self, active: List[int], toks: np.ndarray) -> None:
+        """Appends each active slot's token and frees finished slots."""
         self.steps += 1
         now = time.monotonic()
         for slot in active:
@@ -136,7 +161,6 @@ class ServingEngine:
                 req.finished_at = now
                 self.completed[req.rid] = req
                 self._slots[slot] = None
-        return len(active)
 
     def run_until_done(self, max_steps: int = 100_000) -> None:
         for _ in range(max_steps):
@@ -150,10 +174,12 @@ class ServingEngine:
                   serve_cfg: Optional[ServeConfig] = None, policy=None):
         """WarmSwap replica bring-up: live-migrate the base image from the pool."""
         from repro.core.migration import RestorePolicy
-        restored = manager.request_migration(image_id, policy or RestorePolicy.BULK)
-        params = restored.as_pytree()
-        manager.release(image_id)
-        return cls(cfg, params, serve_cfg)
+        policy = policy or RestorePolicy.BULK
+        with span("from_pool", policy=policy.value):
+            restored = manager.request_migration(image_id, policy)
+            params = restored.as_pytree()
+            manager.release(image_id)
+            return cls(cfg, params, serve_cfg)
 
     # ------------------------------------------------------------------ metrics
     def metrics(self) -> Dict[str, float]:
