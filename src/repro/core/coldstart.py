@@ -221,8 +221,9 @@ class ColdStartOrchestrator:
         if policy == RestorePolicy.LAZY and touch is not None:
             for key in touch:                                  # sparse touch set
                 restored.fault(key)
-            leaves = {k: restored.fault(k) for k in touch}
-            params = leaves                                   # partial residency
+            # partial residency; wait for the touched leaves' transfers so
+            # that migration, not execution, pays for them
+            params = jax.block_until_ready({k: restored.fault(k) for k in touch})
         else:
             for key in restored.metadata.page_table.order[:1]:
                 restored.fault(key)                           # first fault

@@ -3,14 +3,23 @@
 Implements all four prototypes measured in the paper's Table 2:
 
   * ``BULK``          — WarmSwap bulk ("initiative") restore: on the first page fault
-                        the page server streams ALL remaining pages in the background,
-                        in layer order, overlapping with the function's own work.
+                        the page server streams ALL remaining pages in the
+                        background, in layer order (each device leaf on to the
+                        device), overlapping with the function's own work.
   * ``LAZY``          — WarmSwap lazy restore: every fault fetches exactly the pages
                         of the faulting leaf, paying per-fault latency each time.
   * ``NO_PAGESERVER`` — copy the whole serialized image into the container, then
                         restore (the paper's "w/o Page Server" variant).
   * ``NO_LAZY``       — transfer every page through the page server *before*
                         execution begins (the paper's "w/o Lazy Migration" variant).
+
+A restore puts each leaf back where it lived when the image was built. A leaf
+built as a ``jax.Array`` (model parameters) is read in place from the pages the
+page server returned and handed to ``jax.device_put`` as it installs, so its
+host-to-device transfer overlaps the fetch of the next leaf; ``as_pytree`` waits
+for the transfers, so migration, not the first call that uses the parameters,
+pays for them. A leaf built in host memory (the ``py-base`` runtime blob, which
+no device code reads) stays in host memory.
 
 The page server models the provider-side transport: a local pool moves pages at
 host-memcpy speed; a remote pool adds a configurable per-request latency and
@@ -25,10 +34,12 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core.image import ImageMetadata, LiveDependencyImage
-from repro.core.pages import materialize_leaf
+from repro.core.pages import LeafEntry
 from repro.runtime.tracing import span
 
 
@@ -107,6 +118,21 @@ class PageServer:
         return pages
 
 
+def _restore_leaf(pages: np.ndarray, e: LeafEntry) -> Any:
+    """One leaf, from the pages that hold it, where it lived when the image
+    was built.
+
+    The pages are read in place (every leaf starts on a page boundary). A
+    device leaf is handed to ``jax.device_put`` with no device, so it stays
+    uncommitted as the parameters of a freshly built model are;
+    ``device_put`` returns before its transfer ends, and the pages are dropped
+    once it has. A host leaf is a view of ``pages``. Either way the leaf may
+    alias ``pages`` (``device_put`` aliases host memory on a CPU backend), which
+    must therefore be a private copy, never a view of the pool's store."""
+    leaf = pages.reshape(-1)[: e.nbytes].view(jnp.dtype(e.dtype)).reshape(e.shape)
+    return jax.device_put(leaf) if e.on_device else leaf
+
+
 class RestoredImage:
     """Container-side restored dependency: leaves materialize through the chosen
     policy; ``wait_all()`` blocks until the image is fully resident."""
@@ -118,7 +144,7 @@ class RestoredImage:
         self.policy = policy
         self._server = server
         self._table = metadata.page_table
-        self._local: Dict[str, np.ndarray] = {}   # leaf key -> materialized array
+        self._local: Dict[str, Any] = {}   # leaf key -> restored leaf
         self._events: Dict[str, threading.Event] = {k: threading.Event()
                                                     for k in self._table.order}
         self._claim_lock = threading.Lock()
@@ -146,20 +172,15 @@ class RestoredImage:
             return True
 
     def _install_leaf(self, key: str) -> None:
-        """Fetch + materialize one leaf. Caller must have won ``_claim(key)``.
+        """Fetch one leaf's pages and restore it (a device leaf's transfer is
+        started, not waited for). Caller must have won ``_claim(key)``.
 
         On failure the claim is released and the event set anyway so waiters
         wake up and surface the error instead of blocking forever."""
         try:
             e = self._table.entries[key]
-            pages = self._server.fetch_pages(e.first_page, e.n_pages)
-            raw = pages.reshape(-1)[: e.nbytes]
-            dt = np.dtype(e.dtype) if e.dtype != "bfloat16" else None
-            if dt is None:
-                import ml_dtypes
-                dt = np.dtype(ml_dtypes.bfloat16)
-            self._local[key] = np.frombuffer(raw.tobytes(),
-                                             dtype=dt).reshape(e.shape)
+            self._local[key] = _restore_leaf(
+                self._server.fetch_pages(e.first_page, e.n_pages), e)
         except BaseException as exc:
             with self._claim_lock:
                 self._claimed.discard(key)
@@ -212,7 +233,7 @@ class RestoredImage:
         self._stream_thread.start()
 
     # -- the fault path ------------------------------------------------------------
-    def fault(self, key: str) -> np.ndarray:
+    def fault(self, key: str) -> Any:
         """First touch of a leaf by the executing function (userfaultfd
         analogue).
 
@@ -220,8 +241,10 @@ class RestoredImage:
             key: leaf path in the image's page table.
 
         Returns:
-            The materialized leaf array. Under ``BULK`` the first fault also
-            kicks off the background stream for the remaining leaves.
+            The restored leaf; a device leaf's transfer may still be in
+            flight (the consumer's first use, or ``as_pytree``, waits). Under ``BULK`` the first
+            fault also kicks off the background stream for the remaining
+            leaves.
         """
         if self._events[key].is_set() and key in self._local:
             return self._local[key]
@@ -262,12 +285,12 @@ class RestoredImage:
         return len(self._local) / max(len(self._events), 1)
 
     def as_pytree(self) -> Any:
-        """Full parameter pytree (blocks until resident)."""
-        import jax
+        """Full parameter pytree (blocks until every leaf has been fetched and
+        every device leaf's transfer has ended)."""
         t = self._table
         with span("restore", bytes=t.nbytes_payload, pages=t.n_pages):
             self.wait_all()
-            leaves = [self._local[k] for k in t.tree_order]
+            leaves = jax.block_until_ready([self._local[k] for k in t.tree_order])
             return jax.tree_util.tree_unflatten(self.treedef, leaves)
 
 
@@ -300,12 +323,8 @@ class MigrationClient:
             pages = server.fetch_pages(0, md.page_table.n_pages)
             for key in md.page_table.order:
                 e = md.page_table.entries[key]
-                raw = pages[e.first_page: e.first_page + e.n_pages].reshape(-1)[: e.nbytes]
-                dt = np.dtype(e.dtype) if e.dtype != "bfloat16" else None
-                if dt is None:
-                    import ml_dtypes
-                    dt = np.dtype(ml_dtypes.bfloat16)
-                restored._local[key] = np.frombuffer(raw.tobytes(), dtype=dt).reshape(e.shape)
+                restored._local[key] = _restore_leaf(
+                    pages[e.first_page: e.first_page + e.n_pages], e)
                 restored._events[key].set()
             restored._claimed.update(md.page_table.order)
         return restored

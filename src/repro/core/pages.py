@@ -33,6 +33,7 @@ class LeafEntry:
     n_pages: int
     offset: int              # byte offset of this leaf inside its first page == 0 here
     layer_index: int         # streaming order group
+    on_device: bool = False  # built as a jax.Array: a restore puts it on the device
 
     def to_json(self) -> dict:
         return dataclasses.asdict(self)
@@ -131,7 +132,7 @@ def paginate(params: Any, page_size: int = DEFAULT_PAGE_SIZE
         entries[key] = LeafEntry(
             key=key, shape=tuple(arr.shape), dtype=str(arr.dtype),
             nbytes=len(raw), first_page=page_cursor, n_pages=n_pages,
-            offset=0, layer_index=li)
+            offset=0, layer_index=li, on_device=isinstance(leaf, jax.Array))
         page_cursor += n_pages
     store = (np.concatenate(chunks, axis=0) if chunks
              else np.zeros((0, page_size), np.uint8))

@@ -430,7 +430,8 @@ class DependencyManager:
     def reshard_image(self, image_id: str,
                       transform: Callable[[Any], Any]) -> None:
         """Rebuild an image's pages under a new layout (elastic mesh change) without
-        re-running the original initialization."""
+        re-running the original initialization. ``transform`` gets the leaves as
+        host arrays; those it returns as ``jax.Array``s restore to the device."""
         img = self._ensure_live(image_id)
         params = transform(img.params())
         def builder():

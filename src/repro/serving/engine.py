@@ -14,11 +14,15 @@ The engine owns a fixed pool of decode slots over one batched decode state:
 Replica bring-up is WarmSwap's job: ``ServingEngine.from_pool`` live-migrates the
 base-model image out of the DependencyManager (compile-cache + page stream) instead
 of cold-loading from a store — this is also the node-failure recovery path
-(runtime/fault_tolerance.py measures it).
+(runtime/fault_tolerance.py measures it). Parameters built on the device are
+restored to the device: their host-to-device transfer is part of bring-up,
+charged to migration, and no prefill or decode step copies them again
+(``h2d_bytes`` is 0).
 """
 from __future__ import annotations
 
 import collections
+import gc
 import itertools
 import time
 from dataclasses import dataclass, field
@@ -176,6 +180,12 @@ class ServingEngine:
         from repro.core.migration import RestorePolicy
         policy = policy or RestorePolicy.BULK
         with span("from_pool", policy=policy.value):
+            # a dropped replica caught in a reference cycle keeps its
+            # parameters and decode state in device memory until the cyclic
+            # collector runs: return that memory before claiming a new one's.
+            # bench/systems/endpoint.py makes such cycles (it stores wrappers
+            # of a replica's methods on the replica); no caller in src/ does.
+            gc.collect()
             restored = manager.request_migration(image_id, policy)
             params = restored.as_pytree()
             manager.release(image_id)

@@ -2,6 +2,7 @@
 (paper Figs. 5/6, Table 2 semantics)."""
 import tempfile
 
+import jax
 import numpy as np
 import pytest
 
@@ -79,6 +80,17 @@ def test_prebaking_memory_scales_with_functions(stack):
     orch.cold_start_warmswap("helloworld")
     orch.cold_start_warmswap("pyaes")
     assert mgr.pool_bytes() == pool_before  # pool unchanged: image shared
+
+
+@pytest.mark.parametrize("policy", list(RestorePolicy))
+def test_warmswap_restores_each_image_where_it_was_built(stack, policy):
+    """A model image's parameters come back on the device; the py-base
+    runtime blob, which no device code reads, stays in host memory."""
+    _, _, orch = stack
+    inst_m, _ = orch.cold_start_warmswap("lr_serving", policy=policy)
+    inst_p, _ = orch.cold_start_warmswap("helloworld", policy=policy)
+    assert all(isinstance(x, jax.Array) for x in jax.tree.leaves(inst_m.params))
+    assert all(isinstance(x, np.ndarray) for x in jax.tree.leaves(inst_p.params))
 
 
 def test_prebaked_cold_start_works(stack):

@@ -82,6 +82,39 @@ def test_all_policies_restore_identical_params(policy):
         assert np.allclose(np.asarray(a), np.asarray(b))
 
 
+def _mixed_params():
+    """float32 and bfloat16 leaves built on the device, and a byte blob built in
+    host memory (as the py-base runtime image is)."""
+    p = _params()
+    return {**p, "half": jnp.asarray(p["a"], jnp.bfloat16),
+            "blob": np.random.default_rng(0).integers(0, 255, 3000, dtype=np.uint8)}
+
+
+@pytest.mark.parametrize("policy", list(RestorePolicy))
+def test_restore_puts_leaves_back_where_built_byte_equal_and_apart_from_the_pool(policy):
+    src = _mixed_params()
+    mgr = DependencyManager(page_size=1024)
+    mgr.register_image("img", "test", lambda: src)
+    restored = mgr.request_migration("img", policy)
+    out = restored.as_pytree()
+    pairs = list(zip(jax.tree_util.tree_leaves(src), jax.tree_util.tree_leaves(out)))
+    for a, b in pairs:
+        if isinstance(a, jax.Array):
+            assert isinstance(b, jax.Array)
+            assert b.devices() == {jax.devices()[0]}
+        else:
+            assert isinstance(b, np.ndarray)             # stays in host memory
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert np.array_equal(np.asarray(a).view(np.uint8),
+                              np.asarray(b).view(np.uint8))
+    # the restored leaves hold no view of the pool: overwriting the store in
+    # place leaves every restored value as it was
+    mgr._ensure_live("img").store[...] = 0xFF
+    for a, b in pairs:
+        assert np.array_equal(np.asarray(a).view(np.uint8),
+                              np.asarray(b).view(np.uint8))
+
+
 def test_lazy_restore_transfers_only_touched_pages():
     mgr = DependencyManager(page_size=1024)
     mgr.register_image("img", "test", lambda: _params(d=128))

@@ -1,6 +1,8 @@
 """Serving engine (continuous batching), fleet scheduler (stragglers), and
 fault-tolerance (supervisor rollback determinism, pool-based replica recovery)."""
+import gc
 import tempfile
+import weakref
 
 import jax
 import jax.numpy as jnp
@@ -136,3 +138,24 @@ def test_replica_failure_pool_recovery():
     rid = eng.submit(prompt)
     eng.run_until_done()
     assert eng.completed[rid].tokens == ref
+
+
+def test_bring_up_frees_a_dropped_replica_held_in_a_cycle():
+    """A replica dropped while a reference cycle holds it (a wrapper of one of
+    its methods, stored on it) gives its device memory back by the next
+    bring-up, even with the cyclic collector off."""
+    mgr = DependencyManager()
+    mgr.register_image("base", CFG.name, lambda: PARAMS)
+    scfg = ServeConfig(max_slots=1, max_seq_len=32, max_new_tokens=2)
+    old = ServingEngine.from_pool(mgr, "base", CFG, scfg)
+    step = old.step
+    old.step = lambda: step()
+    gone = weakref.ref(old)
+    del old, step
+    gc.disable()
+    try:
+        new = ServingEngine.from_pool(mgr, "base", CFG, scfg)
+        assert gone() is None
+    finally:
+        gc.enable()
+    assert all(isinstance(x, jax.Array) for x in jax.tree.leaves(new.params))
