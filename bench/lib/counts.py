@@ -16,7 +16,9 @@ DTYPE_BYTES = {"float32": 4, "bfloat16": 2, "float16": 2, "int8": 1}
 
 @dataclass(frozen=True)
 class DenseShape:
-    """The sizes of a dense decoder block stack (GQA attention, gated MLP)."""
+    """The sizes of a dense decoder block stack (GQA attention, gated MLP), and
+    the two constants its reference needs beside them (rotary base, norm
+    epsilon; Hugging Face's defaults where the configuration gives none)."""
     d_model: int
     n_layers: int
     n_heads: int
@@ -26,6 +28,8 @@ class DenseShape:
     vocab_size: int
     qkv_bias: bool
     tie_embeddings: bool = True
+    rope_theta: float = 10000.0
+    norm_eps: float = 1e-6
 
     @classmethod
     def from_config(cls, c: dict) -> "DenseShape":
@@ -37,7 +41,9 @@ class DenseShape:
                                   c["hidden_size"] // c["num_attention_heads"]),
                    d_ff=c["intermediate_size"], vocab_size=c["vocab_size"],
                    qkv_bias=bool(c.get("qkv_bias", False)),
-                   tie_embeddings=bool(c.get("tie_word_embeddings", True)))
+                   tie_embeddings=bool(c.get("tie_word_embeddings", True)),
+                   rope_theta=float(c.get("rope_theta", 10000.0)),
+                   norm_eps=float(c.get("rms_norm_eps", 1e-6)))
 
     # -- parameters --------------------------------------------------------------
     def layer_matmul_params(self) -> int:

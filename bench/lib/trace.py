@@ -5,12 +5,13 @@ Two stages, so that a recorded trace can be checked in as a small fixture:
 
 1. :func:`events_from_xplane` reads the profiler's ``.xplane.pb`` with
    ``jax.profiler.ProfileData`` and keeps the device operations (the ``XLA Ops``
-   line of each device plane) and the harness's own host spans (annotations
-   whose names start with ``bench.``), all on the profiler's clock in ns.
+   line of each device plane), the harness's own host spans (annotations
+   whose names start with ``bench.``) and the program's spans (``hotswap.``,
+   with the stats each carries), all on the profiler's clock in ns.
 2. :func:`reduce_window` takes those events and a window and computes busy
    time as the union of operation intervals, the idle share, the operations
    that took most time, and the idle time by what the host was doing: the
-   innermost harness span open at that moment.
+   innermost span, the harness's or the program's, open at that moment.
 """
 from __future__ import annotations
 
@@ -23,9 +24,10 @@ from typing import Dict, List, Optional, Sequence, Tuple
 Interval = Tuple[float, float]
 
 SPAN_PREFIX = "bench."
+PROGRAM_PREFIX = "hotswap."
 DEVICE_OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
-NO_SPAN = "no harness span"
+NO_SPAN = "no span"
 
 
 def op_name(hlo: str, module: str = "") -> str:
@@ -39,20 +41,26 @@ def op_name(hlo: str, module: str = "") -> str:
 
 @dataclass
 class TraceEvents:
-    """Device operations per device and harness host spans, in ns."""
+    """Device operations per device, harness host spans and the program's
+    spans, in ns."""
     device_ops: Dict[str, List[Tuple[str, float, float]]] = field(
         default_factory=dict)          # device plane -> [(op, start, end)]
     host_spans: List[Tuple[str, float, float]] = field(default_factory=list)
+    # [(name, start, end, stats)] of the program's ``hotswap.`` spans
+    program_spans: List[Tuple[str, float, float, Dict]] = field(
+        default_factory=list)
 
     def to_json(self) -> str:
         return json.dumps({"device_ops": self.device_ops,
-                           "host_spans": self.host_spans})
+                           "host_spans": self.host_spans,
+                           "program_spans": self.program_spans})
 
     @classmethod
     def from_json(cls, text: str) -> "TraceEvents":
         d = json.loads(text)
         return cls({k: [tuple(e) for e in v] for k, v in d["device_ops"].items()},
-                   [tuple(e) for e in d["host_spans"]])
+                   [tuple(e) for e in d["host_spans"]],
+                   [tuple(e) for e in d.get("program_spans", [])])
 
     def spans(self, name: str) -> List[Tuple[str, float, float]]:
         """Host spans called ``name`` or ``name#<index>``."""
@@ -61,7 +69,8 @@ class TraceEvents:
 
 
 def events_from_xplane(path: str) -> TraceEvents:
-    """Device operations and harness spans of one ``.xplane.pb`` file."""
+    """Device operations, harness spans and program spans of one
+    ``.xplane.pb`` file."""
     import jax
 
     data = jax.profiler.ProfileData.from_file(path)
@@ -86,7 +95,12 @@ def events_from_xplane(path: str) -> TraceEvents:
                     if e.name.startswith(SPAN_PREFIX):
                         out.host_spans.append(
                             (e.name, e.start_ns, e.start_ns + e.duration_ns))
+                    elif e.name.startswith(PROGRAM_PREFIX):
+                        out.program_spans.append(
+                            (e.name, e.start_ns, e.start_ns + e.duration_ns,
+                             dict(e.stats)))
     out.host_spans.sort(key=lambda s: s[1])
+    out.program_spans.sort(key=lambda s: s[1])
     return out
 
 
@@ -158,7 +172,8 @@ def reduce_window(ev: TraceEvents, lo: float, hi: float,
     n_dev = max(len(busy_by_dev), 1)
     busy_ns = sum(sum(b - a for a, b in m) for m in busy_by_dev.values()) / n_dev
     idle_by_label: Dict[str, float] = collections.defaultdict(float)
-    segs = _span_segments(ev.host_spans, lo, hi)
+    segs = _span_segments(
+        ev.host_spans + [s[:3] for s in ev.program_spans], lo, hi)
     for merged in busy_by_dev.values():
         for a, b, label in segs:
             idle_by_label[label] += (b - a) - covered(merged, a, b)

@@ -1,5 +1,7 @@
 """Plain reference of a dense decoder: GQA attention with rotary positions,
-optional QKV bias, RMSNorm, gated SiLU MLP, tied embeddings.
+optional QKV bias, RMSNorm, gated SiLU MLP, tied embeddings
+(``"model": "dense"``; the functions every family gives are listed in
+:mod:`bench.models`).
 
 It follows the published Qwen1.5 / Llama block and imports nothing of the
 program. Two things follow the layout of the weights the program loads:
@@ -28,6 +30,31 @@ from bench.lib.counts import DenseShape
 
 VOCAB_MULTIPLE = 512
 HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def shape_of(config: Dict) -> DenseShape:
+    """Sizes and constants of a configuration in Hugging Face key names."""
+    return DenseShape.from_config(config)
+
+
+def arch(config: Dict, shape: DenseShape):
+    """The program's ``ArchConfig`` of the model ``shape`` describes."""
+    from repro.models.config import GLOBAL_ATTN, ArchConfig
+    return ArchConfig(
+        name=config["name"], family="dense", n_layers=shape.n_layers,
+        d_model=shape.d_model, n_heads=shape.n_heads,
+        n_kv_heads=shape.n_kv_heads, d_ff=shape.d_ff,
+        vocab_size=shape.vocab_size, head_dim=shape.head_dim,
+        attn_pattern=(GLOBAL_ATTN,), qkv_bias=shape.qkv_bias, mlp="swiglu",
+        rope_theta=shape.rope_theta, norm_eps=shape.norm_eps,
+        tie_embeddings=shape.tie_embeddings)
+
+
+def tiny(config: Dict) -> Dict:
+    """``config`` at a size a CPU test run can hold: every other key kept."""
+    return dict(config, hidden_size=64, num_hidden_layers=2,
+                num_attention_heads=4, num_key_value_heads=4,
+                intermediate_size=128, vocab_size=512)
 
 
 def padded_vocab(shape: DenseShape) -> int:
@@ -115,17 +142,18 @@ def _dot(spec: str, a, b, mode: str):
     return jnp.einsum(spec, a.astype(jnp.bfloat16), b.astype(jnp.bfloat16))
 
 
-def _layer(x, p, positions, shape: DenseShape, theta, eps, mode):
+def _layer(x, p, positions, shape: DenseShape, mode):
     B, S, _ = x.shape
     H, Hkv, hd = shape.n_heads, shape.n_kv_heads, shape.head_dim
     mm = partial(_dot, "bsd,df->bsf", mode=mode)
+    eps = shape.norm_eps
     h = _rmsnorm(x, p["ln1"]["scale"], eps)
     a = p["attn"]
     q, k, v = mm(h, a["wq"]), mm(h, a["wk"]), mm(h, a["wv"])
     if "bq" in a:
         q, k, v = q + a["bq"], k + a["bk"], v + a["bv"]
-    q = _rope(q.reshape(B, S, H, hd), positions, theta)
-    k = _rope(k.reshape(B, S, Hkv, hd), positions, theta)
+    q = _rope(q.reshape(B, S, H, hd), positions, shape.rope_theta)
+    k = _rope(k.reshape(B, S, Hkv, hd), positions, shape.rope_theta)
     v = v.reshape(B, S, Hkv, hd)
     k = jnp.repeat(k, H // Hkv, axis=2)        # query head h reads kv head h // G
     v = jnp.repeat(v, H // Hkv, axis=2)
@@ -158,9 +186,8 @@ def quantize(w, kind: str):
     return jax.tree_util.tree_map_with_path(q, w)
 
 
-@partial(jax.jit, static_argnames=("shape", "theta", "eps", "mode"))
-def forward_hidden(w, tokens, shape: DenseShape, theta: float, eps: float,
-                   mode: str = "float32"):
+@partial(jax.jit, static_argnames=("shape", "mode"))
+def forward_hidden(w, tokens, shape: DenseShape, mode: str = "float32"):
     """Final normed hidden states ``(B, S, d)`` of ``tokens`` ``(B, S)``."""
     dtype = jnp.bfloat16 if mode == "bfloat16" else jnp.float32
     w = jax.tree.map(lambda a: a.astype(dtype), w)
@@ -169,10 +196,10 @@ def forward_hidden(w, tokens, shape: DenseShape, theta: float, eps: float,
     x = jnp.take(w["embed"]["tok"], tokens, axis=0)
 
     def body(x, p):
-        return _layer(x, p, positions, shape, theta, eps, mode), None
+        return _layer(x, p, positions, shape, mode), None
 
     x, _ = jax.lax.scan(body, x, w["unit"][0])
-    return _rmsnorm(x, w["final_norm"]["scale"], eps)
+    return _rmsnorm(x, w["final_norm"]["scale"], shape.norm_eps)
 
 
 @partial(jax.jit, static_argnames=("mode",))
@@ -181,10 +208,4 @@ def logits(w, hidden, mode: str = "float32"):
     float32."""
     tok = w["embed"]["tok"].astype(hidden.dtype)
     return _dot("...d,vd->...v", hidden, tok, mode).astype(jnp.float32)
-
-
-def reference_config(c: Dict) -> Dict:
-    """Rotary base and norm epsilon of a configuration (HF key names)."""
-    return {"theta": float(c.get("rope_theta", 10000.0)),
-            "eps": float(c.get("rms_norm_eps", 1e-6))}
 

@@ -5,7 +5,8 @@
 Run from the root of a checkout. ``BENCHMARK.json`` there names the cell's
 configuration (``bench/configs/<config>.json``) and traffic mix
 (``bench/traffic/<traffic>.json``); the configuration's ``system`` names the
-driver (``bench/systems/<system>.py``), the mix's ``kind`` its generator
+driver (``bench/systems/<system>.py``) and its ``model`` the model family
+(``bench/models/<model>.py``), the mix's ``kind`` its generator
 (``bench/traffic/<kind>.py``) and its ``loop`` how requests arrive
 (``bench/loops/<loop>.py``), and every metric is read by its own file,
 ``bench/metrics/<metric>.py``. The limits of the correctness check are
@@ -125,13 +126,14 @@ def run_cell(config: Dict, mix: Dict, cell: Dict, metrics: List[Dict],
     ``"control_checks"``, the numbers of each control in ``controls``)."""
     import jax
     from bench.lib import trace as tr
-    from bench.lib.counts import DenseShape
     from bench.lib.peaks import device_peaks
     from bench.lib.record import CompileCounter, Recorder
+    from bench.models import module_of
 
     devs = devices_for(cell["chips"], require_tpu)
     peaks = device_peaks(devs[0].device_kind) if require_tpu else None
     rec = Recorder()
+    shape = module_of(config).shape_of(config) if "model" in config else None
     system = importlib.import_module(f"bench.systems.{config['system']}")
     cellobj = system.Cell(config, mix, seed, rec)
     cellobj.setup()
@@ -157,9 +159,6 @@ def run_cell(config: Dict, mix: Dict, cell: Dict, metrics: List[Dict],
     pool = cellobj.pool_bytes()
     cellobj.release()
     checks = cellobj.check(limits)
-    shape = None
-    if config.get("model") == "dense" and "hidden_size" in config:
-        shape = DenseShape.from_config(config)
     run = SimpleNamespace(window=win, spans=rec.spans, compiles=cc.compiles,
                           trace=reduced, events=events, setup_s=setup_s,
                           pool_bytes=pool, config=config, mix=mix, shape=shape,

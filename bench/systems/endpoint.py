@@ -1,7 +1,10 @@
 """A model endpoint served from a HotSwap pool (``"system": "endpoint"``).
 
-The harness makes the weights on the device from the seed, in the dtype the
-configuration serves, and registers them as one pool image. Replicas come up
+The configuration's ``model`` names its family (``bench/models/<model>.py``,
+see :mod:`bench.models`): its sizes, the program's ``ArchConfig``, the weights
+and the plain reference. The harness makes the weights on the device from the
+seed, in the dtype the configuration serves, and registers them as one pool
+image. Replicas come up
 through the program's ``ServingEngine.from_pool`` and serve greedily through
 ``submit`` and ``step``. The harness stamps each request's arrival and the
 moment its first token is visible after a ``step`` returns.
@@ -15,14 +18,14 @@ from __future__ import annotations
 
 import importlib
 import tempfile
+import weakref
 from typing import Dict, List, Optional
 
 import numpy as np
 
 from bench.lib import footprint
-from bench.lib.counts import DenseShape
 from bench.lib.record import Recorder, now
-from bench.models import dense
+from bench.models import module_of
 from bench.systems.common import (Check, FailureLog, Invocation, Window,
                                   leaves_bytes_differing)
 
@@ -34,7 +37,8 @@ CONTROLS = ("bfloat16", "int8", "float8_e4m3fn")
 class Cell:
     def __init__(self, config: Dict, mix: Dict, seed: int, rec: Recorder):
         self.config, self.mix, self.seed, self.rec = config, mix, seed, rec
-        self.shape = DenseShape.from_config(config)
+        self.model = module_of(config)
+        self.shape = self.model.shape_of(config)
         self.dtype = config["served_dtype"]
         self.failures = FailureLog()
         self.finished: List[Dict] = []
@@ -49,19 +53,7 @@ class Cell:
         self._admitting = None
 
     def _weights(self):
-        return dense.make_weights(self.shape, self.seed, self.dtype)
-
-    def _arch(self):
-        from repro.models.config import GLOBAL_ATTN, ArchConfig
-        c, s = self.config, self.shape
-        rc = dense.reference_config(c)
-        return ArchConfig(
-            name=c["name"], family="dense", n_layers=s.n_layers,
-            d_model=s.d_model, n_heads=s.n_heads, n_kv_heads=s.n_kv_heads,
-            d_ff=s.d_ff, vocab_size=s.vocab_size, head_dim=s.head_dim,
-            attn_pattern=(GLOBAL_ATTN,), qkv_bias=s.qkv_bias, mlp="swiglu",
-            rope_theta=rc["theta"], norm_eps=rc["eps"],
-            tie_embeddings=s.tie_embeddings)
+        return self.model.make_weights(self.shape, self.seed, self.dtype)
 
     # ------------------------------------------------------------------ set-up
     def setup(self) -> None:
@@ -74,7 +66,7 @@ class Cell:
         from repro.serving import ServeConfig
 
         m = self.mix
-        self.arch = self._arch()
+        self.arch = self.model.arch(self.config, self.shape)
         self.policy = RestorePolicy(m["policy"])
         self.scfg = ServeConfig(max_slots=m["slots"], max_seq_len=m["max_seq_len"],
                                 max_new_tokens=m["max_new_tokens"])
@@ -95,7 +87,9 @@ class Cell:
     def bring_up(self):
         """A replica from the pool, with the harness's spans around admission
         and the logits of each admission and decode step kept for the check
-        (the work is the program's own)."""
+        (the work is the program's own). The wrappers stored on the replica
+        reach it through a weak proxy, so that dropping the replica frees it
+        at once, without the cyclic collector."""
         from repro.serving import ServingEngine
         engine = ServingEngine.from_pool(self.mgr, self.image_id, self.arch,
                                          self.scfg, policy=self.policy)
@@ -104,22 +98,24 @@ class Cell:
         else:
             self.kept_params[1] = engine.params
         eid = self._engines = self._engines + 1
-        admit, serve_step, submit = engine._admit, engine._serve_step, engine.submit
+        me = weakref.proxy(engine)
+        admit, submit = type(engine)._admit, type(engine).submit
+        serve_step = engine._serve_step      # the jitted step: holds no replica
         queued: List[int] = []
 
         def traced_admit():
             self._admitting = (eid, queued)
             with self.rec.span("bench.admission"):
-                admit()
+                admit(me)
 
         def kept_serve_step(params, state, tok):
             logits, state = serve_step(params, state, tok)
-            rids = [None if r is None else r.rid for r in engine._slots]
+            rids = [None if r is None else r.rid for r in me._slots]
             self.step_logits.append((eid, rids, logits))
             return logits, state
 
         def kept_submit(prompt, max_new_tokens=None):
-            rid = submit(prompt, max_new_tokens)
+            rid = submit(me, prompt, max_new_tokens)
             queued.append(rid)
             return rid
         engine._admit, engine._serve_step = traced_admit, kept_serve_step
@@ -259,7 +255,7 @@ class Cell:
             extra = [Check("requests_short", short, 0),
                      Check("tokens_not_greedy", greedy, 0)]
         else:
-            wc = control_weights(w, control)
+            wc = self.control_weights(w, control)
             wrong = leaves_bytes_differing(jax_host(wc), seed_weights)
             extra = []
         gap, rel = self._compare(w, rows, control)
@@ -275,11 +271,11 @@ class Cell:
 
         if not rows:
             return float("inf"), float("inf")
-        rc = dense.reference_config(self.config)
+        model, shape = self.model, self.shape
         L, V = self.mix["max_seq_len"], self.shape.vocab_size
         wc, mode = None, "float32"
         if control is not None:
-            wc = control_weights(w, control)
+            wc = self.control_weights(w, control)
             mode = "bfloat16" if control == "bfloat16" else "float32"
         gap = rel = 0.0
         for i in range(0, len(rows), 2):
@@ -288,17 +284,16 @@ class Cell:
             for b, r in enumerate(block):
                 full = np.concatenate([r["prompt"], np.asarray(r["tokens"], np.int32)])
                 inp[b, :len(full) - 1] = full[:-1]
-            h = dense.forward_hidden(w, jnp.asarray(inp), self.shape, rc["theta"],
-                                     rc["eps"])
-            hc = None if wc is None else dense.forward_hidden(
-                wc, jnp.asarray(inp), self.shape, rc["theta"], rc["eps"], mode=mode)
+            h = model.forward_hidden(w, jnp.asarray(inp), shape)
+            hc = None if wc is None else model.forward_hidden(
+                wc, jnp.asarray(inp), shape, mode=mode)
             for b, r in enumerate(block):
                 p, n = len(r["prompt"]), len(r["tokens"])
-                ref = np.asarray(dense.logits(w, h[b, p - 1:p - 1 + n]))[:, :V]
+                ref = np.asarray(model.logits(w, h[b, p - 1:p - 1 + n]))[:, :V]
                 if hc is None:
                     got, toks = r["logits"], np.asarray(r["tokens"])
                 else:
-                    got = np.asarray(dense.logits(wc, hc[b, p - 1:p - 1 + n],
+                    got = np.asarray(model.logits(wc, hc[b, p - 1:p - 1 + n],
                                                   mode=mode))[:, :V]
                     toks = got.argmax(-1)
                 served = ref[np.arange(n), toks]
@@ -307,14 +302,13 @@ class Cell:
                 rel = max(rel, float(err.max()))
         return gap, rel
 
-
-def control_weights(w, control: str):
-    """The seed's weights as the ``control`` precision holds them."""
-    import jax
-    import jax.numpy as jnp
-    if control == "bfloat16":
-        return jax.tree.map(lambda a: a.astype(jnp.bfloat16), w)
-    return dense.quantize(w, control)
+    def control_weights(self, w, control: str):
+        """The seed's weights as the ``control`` precision holds them."""
+        import jax
+        import jax.numpy as jnp
+        if control == "bfloat16":
+            return jax.tree.map(lambda a: a.astype(jnp.bfloat16), w)
+        return self.model.quantize(w, control)
 
 
 def jax_host(tree):

@@ -2,15 +2,15 @@
 import time
 
 from bench import run as bench_run
+from bench.models import module_of
 
 CELL = "qwen1.5-0.5b.scale_from_zero"
 
 
 def tiny(config, mix):
-    """The endpoint configuration and mix at a size a test run can hold."""
-    config = dict(config, hidden_size=64, num_hidden_layers=2,
-                  num_attention_heads=4, num_key_value_heads=4,
-                  intermediate_size=128, vocab_size=512)
+    """The endpoint configuration, at the size its model module's ``tiny``
+    gives, and the mix at a size a test run can hold."""
+    config = module_of(config).tiny(config)
     mix = dict(mix, prompt_lengths=[8, 16, 24], length_weights=[0.5, 0.25, 0.25],
                max_new_tokens=8, max_seq_len=32, deck=4, slots=4, burst=4)
     return config, mix

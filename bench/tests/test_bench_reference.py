@@ -16,27 +16,18 @@ CASES = [
 ]
 
 
-def program_cfg(shape, theta):
-    from repro.models.config import GLOBAL_ATTN, ArchConfig
-    return ArchConfig(name="t", family="dense", n_layers=shape.n_layers,
-                      d_model=shape.d_model, n_heads=shape.n_heads,
-                      n_kv_heads=shape.n_kv_heads, d_ff=shape.d_ff,
-                      vocab_size=shape.vocab_size, head_dim=shape.head_dim,
-                      attn_pattern=(GLOBAL_ATTN,), qkv_bias=shape.qkv_bias,
-                      rope_theta=theta)
-
-
-def shape_of(bias, h, hkv):
+def shape_of(bias, h, hkv, theta=1e6):
     return DenseShape(d_model=64, n_layers=3, n_heads=h, n_kv_heads=hkv,
-                      head_dim=16, d_ff=96, vocab_size=300, qkv_bias=bias)
+                      head_dim=16, d_ff=96, vocab_size=300, qkv_bias=bias,
+                      rope_theta=theta)
 
 
 @pytest.mark.parametrize("bias,h,hkv,theta", CASES)
 def test_layout_matches_the_program(bias, h, hkv, theta):
     from repro.models.transformer import init_params
-    shape = shape_of(bias, h, hkv)
+    shape = shape_of(bias, h, hkv, theta)
     ours = dense.make_weights(shape, 1, "bfloat16")
-    theirs = init_params(jax.random.PRNGKey(0), program_cfg(shape, theta),
+    theirs = init_params(jax.random.PRNGKey(0), dense.arch({"name": "t"}, shape),
                          jnp.bfloat16)
     assert jax.tree.structure(ours) == jax.tree.structure(theirs)
     for a, b in zip(jax.tree.leaves(ours), jax.tree.leaves(theirs)):
@@ -46,12 +37,12 @@ def test_layout_matches_the_program(bias, h, hkv, theta):
 @pytest.mark.parametrize("bias,h,hkv,theta", CASES)
 def test_reference_matches_program_forward(bias, h, hkv, theta):
     from repro.models.transformer import forward
-    shape = shape_of(bias, h, hkv)
+    shape = shape_of(bias, h, hkv, theta)
     w = dense.make_weights(shape, 2**40 + 9, "float32")
     toks = jnp.asarray(np.random.default_rng(0).integers(0, 300, (2, 33)),
                        jnp.int32)
-    program, _, _ = forward(w, toks, program_cfg(shape, theta))
-    ref = dense.logits(w, dense.forward_hidden(w, toks, shape, theta, 1e-6))
+    program, _, _ = forward(w, toks, dense.arch({"name": "t"}, shape))
+    ref = dense.logits(w, dense.forward_hidden(w, toks, shape))
     np.testing.assert_allclose(np.asarray(program), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
 
@@ -61,8 +52,8 @@ def test_lower_precision_modes(mode, lo, hi):
     shape = shape_of(True, 4, 4)
     w = dense.make_weights(shape, 3, "float32")
     toks = jnp.asarray(np.arange(40, dtype=np.int32)[None] * 7 % 300)
-    ref = np.asarray(dense.logits(w, dense.forward_hidden(w, toks, shape, 1e6, 1e-6)))
-    h = dense.forward_hidden(w, toks, shape, 1e6, 1e-6, mode=mode)
+    ref = np.asarray(dense.logits(w, dense.forward_hidden(w, toks, shape)))
+    h = dense.forward_hidden(w, toks, shape, mode=mode)
     got = np.asarray(dense.logits(w, h, mode=mode))
     err = np.linalg.norm(got - ref) / np.linalg.norm(ref)
     assert lo < err < hi

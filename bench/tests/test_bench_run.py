@@ -32,6 +32,14 @@ def test_traced_run_reports_per_layer_metrics_and_breakdown():
     assert {"busy_s", "window_s"} <= set(out["device"])
     assert set(out["breakdown"]) == {"device_ops", "idle_gaps"}
     assert len(out["breakdown"]["idle_gaps"]) <= 10
+    # the program's spans and counters, read from the same trace
+    program = {"restore_ms", "admit_wait_ms", "prefill_ms", "decode_ms",
+               "param_h2d_bytes_per_call"}
+    assert program <= set(out["metrics"])
+    assert all(out["metrics"][m]["value"] > 0 for m in program - {
+        "param_h2d_bytes_per_call"})
+    assert out["metrics"]["param_h2d_bytes_per_call"]["value"] == 0
+    assert any(n.startswith("hotswap.") for n, _ in out["breakdown"]["idle_gaps"])
 
 
 def test_metrics_of_a_cell():

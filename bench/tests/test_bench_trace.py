@@ -55,14 +55,31 @@ def test_op_name_drops_the_instruction_text():
 
 def test_events_round_trip_json():
     ev = synthetic()
+    ev.program_spans = [("hotswap.admit", 42, 68, {"rid": 3, "queued_us": 7}),
+                        ("hotswap.from_pool", 80, 90, {"policy": "bulk"})]
     back = tr.TraceEvents.from_json(ev.to_json())
     assert back.device_ops == ev.device_ops and back.host_spans == ev.host_spans
+    assert back.program_spans == ev.program_spans
+
+
+def test_idle_gaps_by_the_innermost_span_of_either_kind():
+    """Harness and program spans nest: admission 40-70 holds the program's
+    admit 42-68, which holds its prefill 45-62; the device is busy 50-60."""
+    ev = tr.TraceEvents()
+    ev.device_ops["/device:TPU:0"] = [("fusion", 50, 60)]
+    ev.host_spans = [("bench.window#0", 0, 100), ("bench.admission#1", 40, 70)]
+    ev.program_spans = [("hotswap.admit", 42, 68, {"queued_us": 5}),
+                        ("hotswap.prefill", 45, 62, {"h2d_bytes": 0})]
+    gaps = dict(tr.reduce_window(ev, 0, 100)["breakdown"]["idle_gaps"])
+    assert gaps == pytest.approx({"bench.window": 70e-9, "bench.admission": 4e-9,
+                                  "hotswap.admit": 9e-9, "hotswap.prefill": 7e-9})
 
 
 def test_recorded_chip_trace():
     """A trace of fnbench.cold_models recorded on a TPU v5e: device operations
     exist, lie inside the window and are a small share of it."""
     ev = tr.TraceEvents.from_json(gzip.decompress(FIXTURE.read_bytes()).decode())
+    assert ev.program_spans == []       # recorded before they were kept
     lo, hi = tr.window_of(ev)
     r = tr.reduce_window(ev, lo, hi)
     assert 0 < r["busy_s"] < r["window_s"]
